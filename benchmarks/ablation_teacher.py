@@ -35,4 +35,7 @@ def run(quick: bool = True, duration: float = 120.0, seed: int = 11):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     run()

@@ -51,6 +51,16 @@ def pretrained(steps: int = 600):
     return params
 
 
+def roofline_device_kind() -> str | None:
+    """The running device's kind when it has published peaks
+    (`launch.mesh.CHIP_PEAKS`), else None: a CPU run reports no roofline
+    share (not measured)."""
+    from repro.launch.mesh import CHIP_PEAKS
+
+    kind = jax.devices()[0].device_kind
+    return kind if kind in CHIP_PEAKS else None
+
+
 def emit(name: str, us_per_call: float, derived: str) -> None:
     print(f"{name},{us_per_call:.1f},{derived}")
 

@@ -32,4 +32,7 @@ def run(quick: bool = True, duration: float = 180.0):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     run()

@@ -28,4 +28,7 @@ def run(per_frame: dict | None = None, quick: bool = True):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     run()

@@ -64,4 +64,7 @@ def run(quick: bool = True):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     run()

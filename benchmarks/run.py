@@ -67,4 +67,7 @@ def main(argv=None) -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     main()

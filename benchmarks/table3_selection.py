@@ -28,4 +28,7 @@ def run(quick: bool = True, duration: float = 120.0, gamma: float = 0.05, seed: 
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     run()

@@ -1,14 +1,6 @@
 #!/usr/bin/env bash
 # CI entry point: tier-1 tests + a fast serving-runtime smoke.
 # Run from the repo root:  bash scripts/ci.sh
-#
-# The gate must be green on a clean tree, so the one module that is
-# known-red in accelerator-less containers (tests/test_dryrun_small.py —
-# 7 env failures, present since the seed; see ROADMAP) is excluded from
-# the gating run. tests/test_kernels.py rejoined the gate in PR 7 (its
-# failures were a pltpu.CompilerParams rename, fixed with a compat
-# shim). Run the full tier-1 command
-# (`PYTHONPATH=src python -m pytest -x -q`) on accelerator hosts.
 set -uo pipefail
 cd "$(dirname "$0")/.."
 
@@ -55,9 +47,8 @@ if [ "${1:-}" = "--sharded" ]; then
     exit $?
 fi
 
-echo "== tier-1 gate: pytest (minus known env-red modules) =="
-python -m pytest -q \
-    --ignore=tests/test_dryrun_small.py
+echo "== tier-1 gate: pytest =="
+python -m pytest -q
 tier1=$?
 
 echo "== serving smoke: benchmarks.serving_scale --smoke =="

@@ -23,28 +23,21 @@ through `core.batched.set_kernel_mode` (``"xla"`` default |
 and caches the measured winner). See ROADMAP item 5 for how the kernels'
 achieved-fraction-of-roofline lands in ``BENCH_serving.json``.
 
-Interpret mode: kernels default to ``interpret=None`` → resolved by
-`interpret_default()`: interpret only when the default jax backend is CPU
-(override with the ``REPRO_PALLAS_INTERPRET`` env var or the kwarg), so
-accelerator hosts stop silently running kernels in the interpreter. On
-this CPU container interpret mode measures CORRECTNESS (byte-identical
-outputs, CI-gated via ``scripts/ci.sh`` → ``kernels_bench --kernels``),
-not speed — the roofline fractions it reports are the analytic story,
-the wall-clock one needs a real accelerator.
+Interpret mode: the low-level kernel functions take ``interpret`` as a
+required keyword; the jit'd wrappers take ``interpret=None``, resolved by
+`interpret_default()`: interpret only when the default jax backend is CPU,
+so an accelerator host always runs the kernels compiled. On CPU interpret
+mode measures CORRECTNESS (byte-identical outputs, CI-gated via
+``scripts/ci.sh`` → ``kernels_bench --kernels``), never speed.
 """
 from __future__ import annotations
 
-import os
-
 
 def interpret_default() -> bool:
-    """Whether Pallas kernels should run in interpret mode when the caller
-    passed ``interpret=None``: yes only on a CPU default backend (there is
-    no Mosaic there), overridable via ``REPRO_PALLAS_INTERPRET=0/1``.
-    Resolved at trace time — the backend does not change mid-process."""
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env.strip().lower() not in ("0", "false", "")
+    """Whether Pallas kernels run in interpret mode when the caller passed
+    ``interpret=None``: yes only on a CPU default backend (there is no
+    Mosaic there). Resolved at trace time — the backend does not change
+    mid-process."""
     import jax
 
     return jax.default_backend() == "cpu"

@@ -25,10 +25,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# renamed TPUCompilerParams -> CompilerParams across jax releases
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams")
-
 NEG_INF = -1e30
 
 
@@ -86,8 +82,8 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
-def flash_attention_4d(q, k, v, *, causal=True, window=0, softcap=0.0,
-                       block_q=128, block_k=128, interpret=True):
+def flash_attention_4d(q, k, v, *, interpret: bool, causal=True, window=0,
+                       softcap=0.0, block_q=128, block_k=128):
     """q: (B, H, Sq, hd); k, v: (B, KV, Skv, hd) with H % KV == 0.
     Returns (B, H, Sq, hd)."""
     B, H, Sq, hd = q.shape
@@ -122,7 +118,7 @@ def flash_attention_4d(q, k, v, *, causal=True, window=0, softcap=0.0,
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, hd), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

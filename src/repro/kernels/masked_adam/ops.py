@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import resolve_interpret, stacking
-from repro.kernels.masked_adam.masked_adam import (LANES, masked_adam_2d,
+from repro.kernels.masked_adam.masked_adam import (masked_adam_2d,
                                                    masked_adam_stacked_3d)
 
 
@@ -30,7 +30,7 @@ def _to_2d(x, n_pad):
     flat = x.reshape(-1)
     if n_pad:
         flat = jnp.pad(flat, (0, n_pad))
-    return flat.reshape(-1, LANES)
+    return flat.reshape(-1, stacking.LANES)
 
 
 @functools.partial(jax.jit, static_argnames=("b1", "b2", "eps", "interpret"))
@@ -42,7 +42,7 @@ def masked_adam_leaf(p, g, m, v, b, bc, *, b1=0.9, b2=0.999, eps=1e-8,
     interpret = resolve_interpret(interpret)
     shape = p.shape
     n = p.size
-    n_pad = (-n) % LANES
+    n_pad = stacking.padded_rows(n) * stacking.LANES - n
     args = [_to_2d(a, n_pad) for a in (p, g, m, v)]
     bmask = _to_2d(b.astype(jnp.float32), n_pad)
     bc2 = jnp.asarray(bc, jnp.float32).reshape(1, 1)
@@ -65,7 +65,7 @@ def masked_adam_stacked(params, grads, state, mask, *, lr=1e-3, b1=0.9,
     / ``grads`` / ``mask`` and ``state``'s moment trees all carry a
     leading session axis; ``state.count`` is (B,) so sessions at different
     Adam step counts get their own bias correction (fed to the kernel as a
-    per-session grid scalar). Returns ``(params', state', u)`` with ``u``
+    (B,) SMEM array indexed by the session's grid step). Returns ``(params', state', u)`` with ``u``
     float32 like the tree_map path. Designed to be traced inside the
     cached phase executables (`core.batched`) — under jit the per-struct
     `stacking.stack_plan` keeps retracing flat.
@@ -76,7 +76,7 @@ def masked_adam_stacked(params, grads, state, mask, *, lr=1e-3, b1=0.9,
     bc = lr * jnp.sqrt(1.0 - b2 ** i32) / (1.0 - b1 ** i32)
     plan = stacking.stack_plan(params)
     b_sessions = plan.b
-    bc2 = bc.reshape(b_sessions, 1)
+    bc = bc.astype(jnp.float32).reshape(b_sessions)
 
     leaves_p = jax.tree.leaves(params)
     leaves_g = jax.tree.leaves(grads)
@@ -96,7 +96,7 @@ def masked_adam_stacked(params, grads, state, mask, *, lr=1e-3, b1=0.9,
         bb = stacking.flatten_group(leaves_b, group, b_sessions,
                                     transform=lambda l: l.astype(jnp.float32))
         po, mo, vo, uo = masked_adam_stacked_3d(
-            pb, gb, mb, vb, bb, bc2, b1=b1, b2=b2, eps=eps,
+            pb, gb, mb, vb, bb, bc, b1=b1, b2=b2, eps=eps,
             interpret=interpret)
         stacking.unflatten_group(po, group, b_sessions, plan.shapes, out=out_p)
         stacking.unflatten_group(mo, group, b_sessions, plan.shapes, out=out_m)

@@ -22,8 +22,8 @@ def _kernel(x_ref, w_ref, o_ref, *, eps):
     o_ref[...] = (x * jax.lax.rsqrt(var + eps) * (1.0 + w)).astype(o_ref.dtype)
 
 
-def rms_norm_2d(x, w, *, eps: float = 1e-6, block_rows: int = BLOCK_ROWS,
-                interpret: bool = True):
+def rms_norm_2d(x, w, *, interpret: bool, eps: float = 1e-6,
+                block_rows: int = BLOCK_ROWS):
     """x: (R, d); w: (d,). Returns (R, d)."""
     R, d = x.shape
     br = min(block_rows, R)

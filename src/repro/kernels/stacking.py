@@ -3,10 +3,10 @@
 The stacked Pallas kernels (masked-Adam, bit-pattern top-k) want each
 session's parameters as ONE lane-aligned buffer — ``(B, rows, 128)`` — so a
 whole fused group moves through HBM in a single grid sweep instead of one
-dispatch per leaf. The flatten is reshape + concat + zero-pad and the
-unflatten is slice + reshape: all bit-exact re-layouts, so a kernel output
-unstacks to exactly the per-leaf arrays the tree_map path would have
-produced.
+dispatch per leaf. The flatten is reshape + concat + zero-pad (rows padded
+to whole TPU tiles, `padded_rows`) and the unflatten is slice + reshape:
+all bit-exact re-layouts, so a kernel output unstacks to exactly the
+per-leaf arrays the tree_map path would have produced.
 
 A `StackPlan` caches the host-side bookkeeping per shape/dtype struct —
 leaf order grouped by dtype, per-leaf sizes and offsets, pad amount, row
@@ -24,6 +24,32 @@ import jax.numpy as jnp
 import numpy as np
 
 LANES = 128
+SUBLANES = 8  # a TPU tile is (8, 128): row blocks must be multiples of 8
+BLOCK_ALIGN = 64  # row alignment above BLOCK_ALIGN rows
+
+
+def padded_rows(n: int) -> int:
+    """Rows of the ``(rows, LANES)`` buffer that holds ``n`` values.
+
+    A multiple of `SUBLANES`, so every row block a kernel takes is a legal
+    TPU tile; above `BLOCK_ALIGN` rows a multiple of `BLOCK_ALIGN`, so a
+    divisor of at least 64 rows exists (`row_block`) and no buffer falls
+    back to 8-row tiles."""
+    rows = -(-n // LANES)
+    align = SUBLANES if rows <= BLOCK_ALIGN else BLOCK_ALIGN
+    return -(-rows // align) * align
+
+
+def row_block(rows: int, block_rows: int) -> int:
+    """The largest multiple of `SUBLANES` that divides ``rows`` and is at
+    most ``block_rows``: the row tile of a grid that covers ``rows``
+    exactly."""
+    if rows % SUBLANES:
+        raise ValueError(f"rows must be a multiple of {SUBLANES}, got {rows}")
+    br = max(min(block_rows, rows) // SUBLANES * SUBLANES, SUBLANES)
+    while rows % br:
+        br -= SUBLANES
+    return br
 
 
 class DtypeGroup(NamedTuple):
@@ -32,7 +58,7 @@ class DtypeGroup(NamedTuple):
     sizes: tuple          # per-session flat size of each leaf
     offsets: tuple        # start of each leaf inside the concat buffer
     n: int                # per-session valid elements (sum of sizes)
-    rows: int             # ceil(n / LANES) — buffer is (B, rows, LANES)
+    rows: int             # padded_rows(n) — buffer is (B, rows, LANES)
 
 
 class StackPlan(NamedTuple):
@@ -89,8 +115,8 @@ def stack_plan(tree) -> StackPlan:
         for s in sizes:
             offsets.append(off)
             off += s
-        rows = -(-off // LANES)  # ceil
-        groups.append(DtypeGroup(dt, idx, sizes, tuple(offsets), off, rows))
+        groups.append(DtypeGroup(dt, idx, sizes, tuple(offsets), off,
+                                 padded_rows(off)))
     plan = StackPlan(b, tuple(groups),
                      tuple(tuple(l.shape) for l in leaves), treedef)
     _PLANS[key] = plan

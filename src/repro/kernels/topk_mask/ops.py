@@ -49,12 +49,12 @@ def stacked_topk_masks(u_stacked, *, frac: float, interpret=None):
         for i in group.indices:
             parts.append(_abs_bits(leaves[i]))
     flat = parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
-    pad = (-n) % stacking.LANES
+    pad = stacking.padded_rows(n) * stacking.LANES - n
     if pad:
         flat = jnp.pad(flat, ((0, 0), (0, pad)))
     bits = flat.reshape(b, -1, stacking.LANES)
     thr_bits = topk_threshold_bits_3d(bits, k, interpret=interpret)
-    thr = jax.lax.bitcast_convert_type(thr_bits.reshape(b), jnp.float32)
+    thr = jax.lax.bitcast_convert_type(thr_bits, jnp.float32)
 
     def leaf_mask(l):
         t = thr.reshape((b,) + (1,) * (l.ndim - 1))

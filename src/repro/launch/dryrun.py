@@ -41,6 +41,9 @@ from repro.models.registry import build
 from repro.roofline import analysis
 from repro.roofline.analytic import ShapeSpec, analytic_cost
 
+# the production meshes are TPU v5e pods (`launch.mesh.CHIP_PEAKS` key)
+TARGET_DEVICE_KIND = "TPU v5 lite"
+
 
 def _abstract_opt_state(params, m_dtype=jnp.float32):
     # paper-faithful baseline: fp32 Adam moments; hillclimb C trades the
@@ -190,7 +193,8 @@ def lower_pair(arch: str, shape_name: str, mesh, *, verbose: bool = True,
     }
 
     facts.update(analysis.roofline_terms(
-        facts["flops"], facts["bytes"], facts["collective_bytes"], chips))
+        facts["flops"], facts["bytes"], facts["collective_bytes"], chips,
+        device_kind=TARGET_DEVICE_KIND))
     facts["useful_flops_ratio"] = (
         facts["model_flops"] / facts["flops"] if facts["flops"] else 0.0
     )

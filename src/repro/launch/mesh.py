@@ -13,6 +13,8 @@ an N-device host-platform mesh (`launch.host_mesh`, forced with
 GPU pool's modeled per-device clocks into real parallel launches."""
 from __future__ import annotations
 
+from typing import NamedTuple
+
 import jax
 
 
@@ -42,7 +44,30 @@ def make_session_mesh(n: int | None = None):
     return jax.make_mesh((n,), ("session",))
 
 
-# TPU v5e hardware constants (per chip) used by the roofline analysis.
-PEAK_FLOPS_BF16 = 197e12  # FLOP/s
-HBM_BW = 819e9  # bytes/s
-ICI_BW = 50e9  # bytes/s per link
+class ChipPeaks(NamedTuple):
+    """Published per-chip peaks of one accelerator kind."""
+
+    flops_bf16: float  # FLOP/s
+    hbm_bytes: float   # bytes of HBM
+    hbm_bw: float      # HBM bytes/s
+    ici_bw: float      # chip-to-chip bytes/s per link
+
+
+# Keyed by `jax.Device.device_kind`. TPU v5e: Google Cloud documentation,
+# "TPU v5e" — 197 TFLOP/s bf16, 16 GB of HBM at 819 GB/s, 1,600 Gbit/s of
+# interchip interconnect over 4 links (50 GB/s each).
+CHIP_PEAKS = {
+    "TPU v5 lite": ChipPeaks(flops_bf16=197e12, hbm_bytes=16e9,
+                             hbm_bw=819e9, ici_bw=50e9),
+}
+
+
+def chip_peaks(device_kind: str) -> ChipPeaks:
+    """The peaks of ``device_kind``; a kind missing from `CHIP_PEAKS` is an
+    error, never a default."""
+    try:
+        return CHIP_PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; known: "
+            f"{sorted(CHIP_PEAKS)}") from None

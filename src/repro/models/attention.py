@@ -188,6 +188,24 @@ def cross_attention(cfg: ModelConfig, p: dict, x, memory):
     return jnp.einsum("bskgh,kghd->bsd", o, p["wo"])
 
 
+def _cache_write(cache, new, slot):
+    """Write one decode position into a (B, R, KV, hd) cache leaf.
+
+    Under an explicit-sharding mesh the new entry carries the activations'
+    batch sharding, while the cache carries its own or none at all;
+    dynamic_update_slice wants both typed alike. The update takes the
+    cache's sharding, or, where the cache is typed without a mesh, the
+    cache takes the update's."""
+    new = new.astype(cache.dtype)
+    c_sh, n_sh = jax.typeof(cache).sharding, jax.typeof(new).sharding
+    if c_sh != n_sh:
+        if not c_sh.mesh.empty:
+            new = jax.sharding.reshard(new, c_sh.spec)
+        elif not n_sh.mesh.empty:
+            cache = jax.sharding.reshard(cache, n_sh.spec)
+    return jax.lax.dynamic_update_slice(cache, new, (0, slot, 0, 0))
+
+
 def decode_self_attention(cfg: ModelConfig, p: dict, x, cache, pos, *, window: int):
     """One-token decode. x: (B,1,d); cache: dict(k,v) each (B,R,KV,hd);
     pos: scalar int32 — current position (same for the whole batch).
@@ -205,10 +223,8 @@ def decode_self_attention(cfg: ModelConfig, p: dict, x, cache, pos, *, window: i
     eff_window = window or cfg.attn_window_override
     ring = bool(cfg.decode_window_slicing and eff_window)
     slot = pos % R if ring else pos
-    k = jax.lax.dynamic_update_slice(cache["k"], k_new.astype(cache["k"].dtype),
-                                     (0, slot, 0, 0))
-    v = jax.lax.dynamic_update_slice(cache["v"], v_new.astype(cache["v"].dtype),
-                                     (0, slot, 0, 0))
+    k = _cache_write(cache["k"], k_new, slot)
+    v = _cache_write(cache["v"], v_new, slot)
     j = jnp.arange(R)
     if ring:
         kp = pos - jnp.mod(pos - j, R)  # absolute position held by slot j

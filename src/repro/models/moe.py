@@ -70,7 +70,7 @@ def moe_apply(cfg: ModelConfig, p: dict, x):
     pos_f = (jnp.cumsum(one_hot, axis=0) * one_hot).sum(axis=-1) - 1  # (T*k,)
     keep = pos_f < cap
 
-    tok_idx = jnp.repeat(jnp.arange(T), k)
+    tok_idx = jnp.arange(T * k) // k  # token of each slot: repeat(arange(T), k)
     contrib = x_flat[tok_idx] * keep[:, None].astype(x_flat.dtype)
     buffer = jnp.zeros((E, cap, d), x_flat.dtype)
     buffer = buffer.at[idx_f, jnp.where(keep, pos_f, cap)].add(contrib, mode="drop")

@@ -16,7 +16,7 @@ import re
 
 import numpy as np
 
-from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
+from repro.launch.mesh import chip_peaks
 
 _DTYPE_BYTES = {
     "pred": 1, "s4": 1, "u4": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2,
@@ -157,10 +157,13 @@ def extrapolate_depth(c1: float, c2: float, n_groups: int) -> float:
 
 
 def roofline_terms(flops: float, hbm_bytes: float, collective_bytes: float,
-                   chips: int) -> dict:
-    t_compute = flops / (chips * PEAK_FLOPS_BF16)
-    t_memory = hbm_bytes / (chips * HBM_BW)
-    t_collective = collective_bytes / (chips * ICI_BW)
+                   chips: int, *, device_kind: str) -> dict:
+    """Lower-bound step terms on ``chips`` chips of ``device_kind`` (its
+    `launch.mesh.CHIP_PEAKS` entry; an unknown kind raises)."""
+    peaks = chip_peaks(device_kind)
+    t_compute = flops / (chips * peaks.flops_bf16)
+    t_memory = hbm_bytes / (chips * peaks.hbm_bw)
+    t_collective = collective_bytes / (chips * peaks.ici_bw)
     terms = {"compute": t_compute, "memory": t_memory, "collective": t_collective}
     return {
         "t_compute_s": t_compute,
@@ -194,18 +197,20 @@ def topk_hbm_bytes(n_coords: int, *, passes: int = 1) -> int:
     return int(n_coords) * (4 * passes + 1)
 
 
-def kernel_roofline_fraction(nbytes: int, measured_s: float,
-                             *, chips: int = 1) -> float | None:
-    """Achieved fraction of the HBM roofline: the analytic memory-bound
-    time for ``nbytes`` of traffic over the measured wall-clock. 1.0 means
-    the launch ran at memory-bandwidth speed; the gap is launch overhead,
-    compute, or wasted re-reads."""
+def kernel_roofline_fraction(nbytes: int, measured_s: float, *,
+                             device_kind: str, chips: int = 1) -> float | None:
+    """Achieved fraction of the HBM roofline of ``device_kind`` (an
+    unknown kind raises): the analytic memory-bound time for ``nbytes`` of
+    traffic over the measured wall-clock. 1.0 means the launch ran at
+    memory-bandwidth speed; the gap is launch overhead, compute, or wasted
+    re-reads."""
+    hbm_bw = chip_peaks(device_kind).hbm_bw
     if not measured_s or measured_s <= 0:
         return None
-    return (nbytes / (chips * HBM_BW)) / measured_s
+    return (nbytes / (chips * hbm_bw)) / measured_s
 
 
-def serving_stage_report(drift: dict) -> dict:
+def serving_stage_report(drift: dict, device_kind: str | None = None) -> dict:
     """Roofline-style summary of the serving pipeline's *measured* stage
     timings, consuming a `repro.serving.obs.drift_report` dict.
 
@@ -219,8 +224,10 @@ def serving_stage_report(drift: dict) -> dict:
 
     Stages whose timing hooks recorded analytic byte traffic (``nbytes`` —
     the masked-Adam and top-k bounds above) additionally report
-    ``roofline_fraction``: measured steady wall-clock against the
-    memory-bound time for those bytes (`kernel_roofline_fraction`)."""
+    ``roofline_fraction`` when the run's ``device_kind`` is given:
+    measured steady wall-clock against the memory-bound time for those
+    bytes on that device (`kernel_roofline_fraction`). Without a device
+    kind (a CPU run) it is None: not measured."""
     stages = {}
     for stage, e in sorted(drift.items()):
         meas, mod = e["measured_steady_s"], e["modeled_steady_s"]
@@ -232,8 +239,10 @@ def serving_stage_report(drift: dict) -> dict:
             "calls": e["calls"],
             "model_efficiency": (mod / meas) if meas > 0 else None,
             "nbytes": nbytes,
-            "roofline_fraction": (kernel_roofline_fraction(nbytes, meas)
-                                  if nbytes else None),
+            "roofline_fraction": (
+                kernel_roofline_fraction(nbytes, meas,
+                                         device_kind=device_kind)
+                if nbytes and device_kind is not None else None),
         }
     measured = {k: v["measured_s"] for k, v in stages.items()}
     return {

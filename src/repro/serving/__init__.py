@@ -112,7 +112,7 @@ fleet-vs-per-object throughput ratio at 10^4.
 Sharded execution (`launch.host_mesh` + `core.batched`): the pool's
 modeled per-device parallelism can run on *real* jax devices.
 ``GPUPool(device_backend="jax")`` binds every modeled `GPUDevice` to a
-concrete ``jax.Device`` (round-robin over the live backend;
+concrete ``jax.Device`` (one live device per slot; a wider pool raises;
 ``"modeled"``, the default, keeps ``jax_device=None`` and is
 bit-identical), and ``core.batched.train_phases_sharded`` executes
 co-resident groups on distinct devices as one multi-device step — either

@@ -1072,14 +1072,16 @@ class ServingEngine:
         clients = [ev.client, *rider_clients]
         for c in clients:
             self._active.discard(c)
-        if len(clients) == 1:
+        jax_device = self.pool.device(gid).jax_device
+        if len(clients) == 1 and jax_device is None:
             deltas = [self.sessions[ev.client].train(ev.time)]
         else:
             # the stacked launch just finished: run the actual fused math —
             # on the granted pool slot's own jax device when the pool binds
-            # one (device_backend="jax"); None places nothing (bit-identical)
+            # one (device_backend="jax"), a lone session included; None
+            # places nothing (bit-identical)
             deltas = train_many([self.sessions[c] for c in clients], ev.time,
-                                device=self.pool.device(gid).jax_device)
+                                device=jax_device)
         self.served.inc(len(clients))
         legacy = self.cfg.streams.legacy
         cost = self.pool.device(gid).cost

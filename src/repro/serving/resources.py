@@ -259,13 +259,12 @@ class GPUPool:
         if costs is None:
             costs = [cost or GPUCostModel()] * max(n_gpus, 1)
         self.devices = [GPUDevice(gid=g, cost=c) for g, c in enumerate(costs)]
-        # device_backend="jax": bind every pool slot to a concrete
+        # device_backend="jax": bind every pool slot to its own concrete
         # jax.Device so fused lifecycles for co-resident groups on
         # *different* slots really dispatch on different devices
-        # (launch.host_mesh forces N host devices on CPU-only hosts).
-        # Round-robin when the pool is wider than the live device list —
-        # the clocks stay per-slot either way, but `distinct_jax_devices`
-        # tells benchmarks how much real parallelism is available.
+        # (launch.host_mesh forces N host devices on CPU-only hosts). A
+        # pool wider than the live device list raises: slots sharing a
+        # device would run serially while the clocks claim parallelism.
         # "modeled" (the default) binds nothing and is bit-identical to
         # the pre-knob pool: no jax import, no device_put, no placement.
         self.device_backend = device_backend
@@ -273,8 +272,12 @@ class GPUPool:
             import jax
 
             live = jax.devices()
+            if len(self.devices) > len(live):
+                raise ValueError(
+                    f"device_backend='jax' binds one device per pool slot: "
+                    f"{len(self.devices)} slots, {len(live)} live devices")
             for d in self.devices:
-                d.jax_device = live[d.gid % len(live)]
+                d.jax_device = live[d.gid]
         self.migration = migration or MigrationModel()
         self.streams = streams or StreamModel()
         self.tracer = None  # flight recorder (serving.obs.Tracer), optional
@@ -308,11 +311,8 @@ class GPUPool:
 
     @property
     def distinct_jax_devices(self) -> int:
-        """How many *different* real devices back the pool (0 = modeled).
-
-        A 4-slot pool on a 1-device host binds 4 slots to the same device:
-        correctness holds but a "sharded" launch is physically serial, so
-        benchmarks gate their wall-clock claims on this being > 1."""
+        """How many *different* real devices back the pool (0 = modeled;
+        the slot count under ``device_backend="jax"``)."""
         return len({id(d.jax_device) for d in self.devices
                     if d.jax_device is not None})
 

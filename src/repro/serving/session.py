@@ -286,17 +286,20 @@ def train_many(sessions: list, t: float, device=None) -> list:
     returned list is delta-or-None per session, in input order.
 
     ``device`` is the granted pool slot's ``jax.Device`` binding
-    (`GPUPool(device_backend="jax")`): the fused stacked launch then runs
-    on that device instead of the default one. None places nothing."""
+    (`GPUPool(device_backend="jax")`): the stacked launch then runs on that
+    device instead of the default one, and so does a lone session's (as a
+    stack of one), so every grant's state lands on its bound device. None
+    places nothing."""
     out: list = [None] * len(sessions)
     fusable = [i for i, s in enumerate(sessions)
                if getattr(s, "ams_session", None) is not None]
     rest = list(range(len(sessions)))
-    if len(fusable) >= 2:
+    if len(fusable) >= 2 or (fusable and device is not None):
         from repro.core.batched import train_phases_fused
 
         deltas = train_phases_fused([sessions[i].ams_session for i in fusable],
-                                    t, device=device)
+                                    t, force_stack=device is not None,
+                                    device=device)
         for i, d in zip(fusable, deltas):
             if d is not None:
                 sessions[i].phases += 1

@@ -65,7 +65,7 @@ def build_sessions(
     return sessions
 
 
-def run_multiclient(
+def build_engine(
     n_clients: int,
     pretrained,
     seg_cfg,
@@ -86,8 +86,9 @@ def run_multiclient(
     serving_cfg: ServingConfig | None = None,
     tracer=None,
     faults=None,
-) -> dict:
-    """Returns mean mIoU across clients + scheduler/network telemetry.
+) -> ServingEngine:
+    """The serving engine `run_multiclient` runs: its sessions (with their
+    students and replay buffers) stay readable after ``engine.run()``.
 
     Seed-era keys (``n_clients``, ``miou_per_client``, ``mean_miou``,
     ``gpu_utilization``, ``phases_served``, ``phases_deferred``) are
@@ -146,6 +147,13 @@ def run_multiclient(
                         else fuse_train),
             streams=(serving_cfg.streams if streams is None else streams),
             faults=(serving_cfg.faults if faults is None else faults))
-    engine = ServingEngine(sessions, policy=policy, cost=cost, cfg=cfg,
-                           tracer=tracer)
-    return engine.run()
+    return ServingEngine(sessions, policy=policy, cost=cost, cfg=cfg,
+                         tracer=tracer)
+
+
+def run_multiclient(n_clients: int, pretrained, seg_cfg, ams_cfg,
+                    **kwargs) -> dict:
+    """Returns mean mIoU across clients + scheduler/network telemetry: the
+    results dict of ``build_engine(...).run()`` (same arguments)."""
+    return build_engine(n_clients, pretrained, seg_cfg, ams_cfg,
+                        **kwargs).run()

@@ -150,6 +150,25 @@ def test_masked_adam_stacked_under_jit_and_grad_context():
     _assert_close(uj, ue)
 
 
+@pytest.mark.parametrize("n", [1, 128, 129, 1024, 8193, 65_537, 2_014_885])
+def test_stacking_rows_are_whole_tpu_tiles(n):
+    """Kernel buffers hold whole (8, 128) tiles and a row block of at
+    least 64 rows divides them above 64 rows, so no Pallas block breaks the
+    TPU tiling rule; padding stays under one alignment unit."""
+    from repro.kernels import stacking
+
+    rows = stacking.padded_rows(n)
+    assert rows * stacking.LANES >= n
+    assert rows % stacking.SUBLANES == 0
+    assert rows - (-(-n // stacking.LANES)) < max(
+        stacking.SUBLANES, stacking.BLOCK_ALIGN if rows > 64 else 0)
+    br = stacking.row_block(rows, 512)
+    assert br % stacking.SUBLANES == 0 and rows % br == 0 and br <= 512
+    assert br >= min(rows, stacking.BLOCK_ALIGN)
+    with pytest.raises(ValueError):
+        stacking.row_block(rows + 1, 512)
+
+
 # ---------------------------------------------------------------------------
 # bit-pattern top-k vs sort-path / counting-search references
 # ---------------------------------------------------------------------------
@@ -331,12 +350,12 @@ def test_build_phase_fn_pallas_matches_xla(mode):
 
 
 def test_interpret_default_backend_and_env(monkeypatch):
-    monkeypatch.delenv("REPRO_PALLAS_INTERPRET", raising=False)
+    """Interpret mode follows the backend and the explicit kwarg only: no
+    environment variable can send a kernel to the interpreter."""
     assert interpret_default() == (jax.default_backend() == "cpu")
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
-    assert interpret_default() is False
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
-    assert interpret_default() is True
-    assert resolve_interpret(None) is True
+    for value in ("0", "1"):
+        monkeypatch.setenv("REPRO_PALLAS_INTERPRET", value)
+        assert interpret_default() == (jax.default_backend() == "cpu")
+    assert resolve_interpret(None) == (jax.default_backend() == "cpu")
     assert resolve_interpret(False) is False
     assert resolve_interpret(True) is True

@@ -57,3 +57,24 @@ def test_bandwidth_ledger():
     up, down = led.kbps(8.0)
     assert up == pytest.approx(1.0)
     assert down == pytest.approx(4.0)
+
+
+def test_compile_cache_dir_from_env_or_fixed_path(monkeypatch, tmp_path):
+    """The persistent compile cache goes where JAX_COMPILATION_CACHE_DIR
+    says, else to the checkout's fixed `.jax_cache` — never a temporary
+    or per-process name."""
+    from repro.launch import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert compile_cache.configure_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == str(tmp_path)
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = compile_cache.configure_compile_cache()
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert path == os.path.join(repo, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+        assert compile_cache.configure_compile_cache() == path  # stable
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

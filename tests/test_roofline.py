@@ -8,7 +8,9 @@ from repro.configs import get_smoke
 from repro.core.masked_adam import MaskedAdamState, init_state
 from repro.launch.steps import make_train_step
 from repro.models.registry import build
-from repro.roofline.analysis import collective_bytes_from_hlo, roofline_terms
+from repro.roofline.analysis import (collective_bytes_from_hlo,
+                                     kernel_roofline_fraction,
+                                     roofline_terms)
 from repro.roofline.analytic import ShapeSpec, analytic_cost
 
 
@@ -87,8 +89,24 @@ def test_analytic_matches_hlo_on_unrolled_smoke():
 
 
 def test_roofline_terms_bottleneck():
-    t = roofline_terms(flops=1e18, hbm_bytes=1e12, collective_bytes=1e12, chips=256)
+    v5e = "TPU v5 lite"
+    t = roofline_terms(flops=1e18, hbm_bytes=1e12, collective_bytes=1e12,
+                       chips=256, device_kind=v5e)
     assert t["bottleneck"] == "compute"
     assert t["t_compute_s"] > t["t_memory_s"]
-    t2 = roofline_terms(flops=1e12, hbm_bytes=1e13, collective_bytes=1e9, chips=256)
+    assert t["t_compute_s"] == 1e18 / (256 * 197e12)
+    t2 = roofline_terms(flops=1e12, hbm_bytes=1e13, collective_bytes=1e9,
+                        chips=256, device_kind=v5e)
     assert t2["bottleneck"] == "memory"
+    assert t2["t_memory_s"] == 1e13 / (256 * 819e9)
+
+
+@pytest.mark.parametrize("kind", ["cpu", "TPU v4", ""])
+def test_roofline_unknown_device_kind_raises(kind):
+    """Peaks come from the device-kind table only: a kind missing from it
+    is an error, never a silent v5e default."""
+    with pytest.raises(ValueError, match="no published peaks"):
+        roofline_terms(flops=1.0, hbm_bytes=1.0, collective_bytes=1.0,
+                       chips=1, device_kind=kind)
+    with pytest.raises(ValueError, match="no published peaks"):
+        kernel_roofline_fraction(1024, 1e-3, device_kind=kind)

@@ -148,11 +148,16 @@ def test_gpupool_device_backend_validates_and_binds():
     assert modeled.device_backend == "modeled"
     assert all(d.jax_device is None for d in modeled.devices)
     assert modeled.distinct_jax_devices == 0
-    bound = GPUPool(n_gpus=3, device_backend="jax")
-    assert [d.gid for d in bound.devices] == [0, 1, 2]
     live = jax.devices()
-    assert bound.jax_devices() == [live[g % len(live)] for g in range(3)]
-    assert bound.distinct_jax_devices == min(3, len(live))
+    n = len(live)
+    bound = GPUPool(n_gpus=n, device_backend="jax")
+    assert [d.gid for d in bound.devices] == list(range(n))
+    assert bound.jax_devices() == list(live)
+    assert bound.distinct_jax_devices == n
+    # a pool wider than the live devices would share a device between
+    # slots and run "parallel" grants serially: refused, not wrapped
+    with pytest.raises(ValueError, match="live devices"):
+        GPUPool(n_gpus=n + 1, device_backend="jax")
 
 
 # ---------------- sharded == fused on the default device ----------------

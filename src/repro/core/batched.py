@@ -49,9 +49,16 @@ GSPMD launch over a cached `launch.mesh.make_session_mesh` sharding
 executable cache); one launch, but numerics only to the PR-7 float32
 tolerance contract (masks and wire bytes stay byte-identical). Per-device
 and whole-batch wall-clock land in `core.timing` ("sharded_device" /
-"train_sharded"), which `obs.drift_report` prices per device against the
-`GPUCostModel` — the modeled-vs-measured audit the serving stack's
-capacity numbers hang off.
+"train_sharded").
+
+Stage records (`core.timing`): the host work around each launch is timed
+where it happens, as sibling spans on the dispatching thread: ``place``
+(stacking the group's state and moving its replay batches to the device,
+with the batch bytes sent) and ``commit`` (pulling the losses, unstacking
+and adopting each member's state), beside ``select_stacked``,
+``train_fused`` and ``encode_stacked``; ``prepare`` (replay sampling) is
+`AMSSession._prepare_phase_deferred`'s. Each is a profiler annotation
+``ams.<stage>`` too, so a trace shows which of them the device waits on.
 """
 from __future__ import annotations
 
@@ -518,17 +525,19 @@ def _launch_stacked(members, device=None, record=True):
     without a serializing block and clock them itself). ``device`` may be
     a ``jax.Device`` or a `Sharding`; None keeps the default placement."""
     ss = [m[1] for m in members]
-    params = stack_trees([s.params for s in ss])
-    opt = stack_trees([s.opt_state for s in ss])
     mask = _stacked_masks(members, True, device=device)
-    # batches: per-session (K, batch, ...) -> scan-major (K, B, batch, ...)
-    frames = jnp.stack([m[3] for m in members], axis=1)
-    labels = jnp.stack([m[4] for m in members], axis=1)
-    if device is not None:
-        # one placement per tree; the mask already lives there, and every
-        # launch below follows its committed inputs onto the same device
-        params, opt = jax.device_put((params, opt), device)
-        frames, labels = jax.device_put((frames, labels), _batch_spec(device))
+    with _place_span(members):
+        params = stack_trees([s.params for s in ss])
+        opt = stack_trees([s.opt_state for s in ss])
+        # batches: per-session (K, batch, ...) -> scan-major (K, B, batch, ...)
+        frames = jnp.stack([m[3] for m in members], axis=1)
+        labels = jnp.stack([m[4] for m in members], axis=1)
+        if device is not None:
+            # one placement per tree; the mask already lives there, and
+            # every launch below follows its committed inputs onto it
+            params, opt = jax.device_put((params, opt), device)
+            frames, labels = jax.device_put((frames, labels),
+                                            _batch_spec(device))
     s0 = ss[0]
     miss0 = _MISSES
     phase = fused_phase_fn(
@@ -537,23 +546,30 @@ def _launch_stacked(members, device=None, record=True):
         k_iters=s0.cfg.k_iters, optimizer=s0.cfg.optimizer,
         lr=s0.cfg.lr, b1=s0.cfg.b1, b2=s0.cfg.b2, eps=s0.cfg.eps,
         momentum=s0.cfg.momentum)
+    first = _MISSES > miss0
     if record and timing.enabled():
         # first launch (a cache miss — including the auto-mode race)
-        # lands in the compile bucket, steady launches in steady-state
-        t0 = time.perf_counter()
-        params, opt, u, losses = phase(params, opt, mask, frames, labels)
-        timing.block((params, opt, u, losses))
+        # lands in the compile bucket, steady launches in steady-state.
         # nbytes: analytic optimizer-update traffic only (the
         # masked-Adam roofline term — forward/backward excluded),
         # B x K x `roofline.analysis.adam_step_hbm_bytes`
-        timing.record("train_fused", time.perf_counter() - t0,
-                      first=_MISSES > miss0,
-                      key=(len(members), s0.cfg.k_iters),
-                      nbytes=(len(members) * s0.cfg.k_iters * 33
-                              * selection.tree_size(s0.params)))
+        with timing.span("train_fused", first=first,
+                         key=(len(members), s0.cfg.k_iters),
+                         nbytes=(len(members) * s0.cfg.k_iters * 33
+                                 * selection.tree_size(s0.params))):
+            params, opt, u, losses = phase(params, opt, mask, frames, labels)
+            timing.block((params, opt, u, losses))
     else:
         params, opt, u, losses = phase(params, opt, mask, frames, labels)
-    return (params, opt, u, losses, mask), _MISSES > miss0
+    return (params, opt, u, losses, mask), first
+
+
+def _place_span(members):
+    """The ``place`` stage of one stacked launch: keyed by the stack's
+    width B, counting the replay batches' bytes sent to the device."""
+    return timing.span("place", key=(len(members),),
+                       nbytes=sum(m[3].nbytes + m[4].nbytes
+                                  for m in members))
 
 
 # ---------------------------------------------------------------------------
@@ -614,13 +630,15 @@ def _launch_spmd(members, group_key, shard_b, sharding):
     from jax.experimental.shard_map import shard_map
 
     ss = [m[1] for m in members]
-    params = stack_trees([s.params for s in ss])
-    opt = stack_trees([s.opt_state for s in ss])
     mask = _stacked_masks(members, True, device=sharding)
-    frames = jnp.stack([m[3] for m in members], axis=1)
-    labels = jnp.stack([m[4] for m in members], axis=1)
-    params, opt = jax.device_put((params, opt), sharding)
-    frames, labels = jax.device_put((frames, labels), _batch_spec(sharding))
+    with _place_span(members):
+        params = stack_trees([s.params for s in ss])
+        opt = stack_trees([s.opt_state for s in ss])
+        frames = jnp.stack([m[3] for m in members], axis=1)
+        labels = jnp.stack([m[4] for m in members], axis=1)
+        params, opt = jax.device_put((params, opt), sharding)
+        frames, labels = jax.device_put((frames, labels),
+                                        _batch_spec(sharding))
     s0 = ss[0]
     miss0 = _MISSES
 
@@ -694,8 +712,11 @@ def train_phases_sharded(session_groups: list, t_now: float, *,
     1 ULP) rather than the per-device dispatch path's bit-identity.
 
     Timing lands per device ("sharded_device", key=(slot, B, K)) and per
-    batch ("train_sharded"); `obs.drift_report` prices both against the
-    pool's `GPUCostModel` — the per-device modeled-vs-measured audit.
+    batch ("train_sharded", from before the first launch to the last
+    device's finish; its ``ams.train_sharded`` annotation covers only the
+    wait, so the launches' own ``place`` and ``select_stacked`` spans stay
+    its siblings in a trace). `obs.drift_report` folds both against the
+    pool's `GPUCostModel` for the engine's results.
 
     Returns a list of per-group result lists (delta-or-None per session,
     ``train_phases_fused`` semantics)."""
@@ -756,16 +777,13 @@ def train_phases_sharded(session_groups: list, t_now: float, *,
                 slots.append((gi, i))
         out, first = _launch_spmd(flat, prepped[0][2], len(prepped[0][1]),
                                   _session_sharding(devs))
-        _block(out)
+        k0 = flat[0][1].cfg.k_iters
+        with timing.span("train_sharded", first=first, start=t0,
+                         key=(len(prepped), len(prepped[0][1]), k0),
+                         nbytes=(len(flat) * k0 * 33
+                                 * selection.tree_size(flat[0][1].params))):
+            _block(out)
         _SHARD_STATS["spmd_launches"] += 1
-        if timing_on:
-            b = len(prepped[0][1])
-            k0 = flat[0][1].cfg.k_iters
-            timing.record(
-                "train_sharded", time.perf_counter() - t0, first=first,
-                key=(len(prepped), b, k0),
-                nbytes=(len(flat) * k0 * 33
-                        * selection.tree_size(flat[0][1].params)))
         flat_results: dict = {}
         _commit_stacked(flat, t_now, out, flat_results)
         for j, (gi, i) in enumerate(slots):
@@ -788,11 +806,19 @@ def train_phases_sharded(session_groups: list, t_now: float, *,
             _block(out)
             return time.perf_counter()
 
-        if len(launches) > 1:
-            with ThreadPoolExecutor(max_workers=len(launches)) as ex:
-                done = list(ex.map(_wait, [l[2] for l in launches]))
-        else:
-            done = [_wait(launches[0][2])]
+        bks = {(len(m), m[0][1].cfg.k_iters) for _, m, _, _ in launches}
+        uniform = bks.pop() if len(bks) == 1 else None
+        with timing.span("train_sharded", start=t0,
+                         first=any(l[3] for l in launches),
+                         key=(len(launches),) + (uniform or ()),
+                         nbytes=sum(len(m) * m[0][1].cfg.k_iters * 33
+                                    * selection.tree_size(m[0][1].params)
+                                    for _, m, _, _ in launches)):
+            if len(launches) > 1:
+                with ThreadPoolExecutor(max_workers=len(launches)) as ex:
+                    done = list(ex.map(_wait, [l[2] for l in launches]))
+            else:
+                done = [_wait(launches[0][2])]
         if timing_on:
             for (gi, members, out, first), t_done in zip(launches, done):
                 s0 = members[0][1]
@@ -801,15 +827,6 @@ def train_phases_sharded(session_groups: list, t_now: float, *,
                     key=(gi, len(members), s0.cfg.k_iters),
                     nbytes=(len(members) * s0.cfg.k_iters * 33
                             * selection.tree_size(s0.params)))
-            bks = {(len(m), m[0][1].cfg.k_iters) for _, m, _, _ in launches}
-            uniform = bks.pop() if len(bks) == 1 else None
-            timing.record(
-                "train_sharded", max(done) - t0,
-                first=any(l[3] for l in launches),
-                key=(len(launches),) + (uniform or ()),
-                nbytes=sum(len(m) * m[0][1].cfg.k_iters * 33
-                           * selection.tree_size(m[0][1].params)
-                           for _, m, _, _ in launches))
     for gi, members, out, _ in launches:
         _commit_stacked(members, t_now, out, results_per[gi])
     return [[results_per[gi].get(i) for i in range(len(sg))]
@@ -818,19 +835,22 @@ def train_phases_sharded(session_groups: list, t_now: float, *,
 
 def _commit_stacked(members, t_now, out, results) -> None:
     """Encode the group's wire deltas (one batched device->host pull) and
-    commit per-member state — the tail every stacked launch shares."""
+    commit per-member state — the tail every stacked launch shares. The
+    commit (losses pulled, state unstacked and adopted) is its own stage,
+    after the encode's."""
     params, opt, u, losses, mask = out
-    losses = np.asarray(losses)
     b = len(members)
     s0 = members[0][1]
     deltas = encode_delta_stack(params, mask, b, s0.cfg.value_dtype)
     _UPDATE_STATS["stacked_encode_launches"] += 1
     _UPDATE_STATS["stacked_encode_sessions"] += b
-    for j, (i, s, _, _, _), p_j, o_j, u_j in zip(
-            range(b), members, unstack_tree(params, b),
-            unstack_tree(opt, b), unstack_tree(u, b)):
-        # the delta is already encoded (batched), so no per-member mask
-        # slice is ever consumed — don't dispatch B tree-slicings for it
-        results[i] = s._commit_phase(t_now, p_j, o_j, u_j,
-                                     float(losses[j]), None,
-                                     delta=deltas[j])
+    with timing.span("commit"):
+        losses = np.asarray(losses)
+        for j, (i, s, _, _, _), p_j, o_j, u_j in zip(
+                range(b), members, unstack_tree(params, b),
+                unstack_tree(opt, b), unstack_tree(u, b)):
+            # the delta is already encoded (batched), so no per-member
+            # mask slice is ever consumed — don't dispatch B tree-slicings
+            results[i] = s._commit_phase(t_now, p_j, o_j, u_j,
+                                         float(losses[j]), None,
+                                         delta=deltas[j])

@@ -8,7 +8,6 @@ the parameters... compressed [with] gzip").
 from __future__ import annotations
 
 import gzip
-import time
 from dataclasses import dataclass
 
 import jax
@@ -68,13 +67,10 @@ def encode_delta(params_new, mask, value_dtype="float16") -> ModelDelta:
     gathered per leaf (never materializing the full flat parameter vector)
     and mask bits are written into a reused scratch buffer before packing.
     Byte-identical to the two-pass flatten/concat encoding."""
-    if not timing.enabled():
-        return _encode_delta_impl(params_new, mask, value_dtype)
-    t0 = time.perf_counter()
-    d = _encode_delta_impl(params_new, mask, value_dtype)
     # pure host work (asarray syncs the device): no compile split needed
-    timing.record("encode_solo", time.perf_counter() - t0,
-                  nbytes=d.total_bytes)
+    with timing.span("encode_solo") as sp:
+        d = _encode_delta_impl(params_new, mask, value_dtype)
+        sp.nbytes = d.total_bytes
     return d
 
 
@@ -166,20 +162,18 @@ def encode_delta_stack(params_stacked, mask_stacked, n_sessions: int,
         _STACK_CACHE[key] = fn
     else:
         _STACK_HITS += 1
-    t0 = time.perf_counter() if timing.enabled() else 0.0
-    vals_dev, bits_dev = fn(params_stacked, mask_stacked)
-    vals = np.asarray(vals_dev)  # ONE stacked pull each, not B x n_leaves
-    bits = np.asarray(bits_dev)
-    out = []
-    for b in range(n_sessions):
-        flat_m = bits[b]
-        out.append(ModelDelta(values=vals[b][flat_m],
-                              packed_mask=_pack_mask_bits(flat_m),
-                              n_total=n_total, value_dtype=value_dtype))
-    if timing.enabled():
-        timing.record("encode_stacked", time.perf_counter() - t0,
-                      first=first, key=(n_sessions,),
-                      nbytes=sum(d.total_bytes for d in out))
+    with timing.span("encode_stacked", first=first,
+                     key=(n_sessions,)) as sp:
+        vals_dev, bits_dev = fn(params_stacked, mask_stacked)
+        vals = np.asarray(vals_dev)  # ONE stacked pull each, not B x leaves
+        bits = np.asarray(bits_dev)
+        out = []
+        for b in range(n_sessions):
+            flat_m = bits[b]
+            out.append(ModelDelta(values=vals[b][flat_m],
+                                  packed_mask=_pack_mask_bits(flat_m),
+                                  n_total=n_total, value_dtype=value_dtype))
+        sp.nbytes = sum(d.total_bytes for d in out)
     return out
 
 

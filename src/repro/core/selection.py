@@ -89,10 +89,9 @@ def gradient_guided_mask(u_tree, frac: float):
     key = _stack_key(u_tree, frac)
     first = key not in _SOLO_SEEN
     _SOLO_SEEN.add(key)
-    t0 = time.perf_counter()
-    out = body(u_tree, frac)
-    timing.block(out)
-    timing.record("select_solo", time.perf_counter() - t0, first=first)
+    with timing.span("select_solo", first=first):
+        out = body(u_tree, frac)
+        timing.block(out)
     return out
 
 
@@ -239,12 +238,10 @@ def stacked_gradient_guided_masks(u_stacked, frac: float):
             _STACK_HITS += 1
         if not timing.enabled():
             return fn(u_stacked)
-        t0 = time.perf_counter()
-        out = fn(u_stacked)
-        timing.block(out)
-        timing.record("select_stacked", time.perf_counter() - t0,
-                      first=first, key=(b,),
-                      nbytes=_select_nbytes(b, per_session))
+        with timing.span("select_stacked", first=first, key=(b,),
+                         nbytes=_select_nbytes(b, per_session)):
+            out = fn(u_stacked)
+            timing.block(out)
         return out
     # kernel_mode("auto"), undecided: race XLA vs Pallas on this real
     # batch — byte-identical outputs make the race numerics-free; one

@@ -15,7 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import selection
+from repro.core import selection, timing
 from repro.core.atr import ATRController
 from repro.core.buffer import ReplayBuffer
 from repro.core.delta import ModelDelta, encode_delta
@@ -178,19 +178,21 @@ class AMSSession:
         """`_prepare_phase` for the fused pipeline: identical RNG
         consumption and batch shapes, but a gradient-guided mask slot is
         returned as None (deferred) so `core.batched` can run one stacked
-        selection launch for the whole group instead of B solo ones."""
+        selection launch for the whole group instead of B solo ones.
+        Timed as the ``prepare`` stage (`core.timing`)."""
         cfg = self.cfg
         if len(self.buffer) == 0:
             return None
-        mask = self._select_mask_or_defer()
-        batches = []
-        for _ in range(cfg.k_iters):
-            batch = self.buffer.sample(self.rng, cfg.batch_size, t_now)
-            if batch is None:  # empty horizon window: jrng consumed, no train
-                return None
-            batches.append(batch)
-        frames = np.stack([b[0] for b in batches])
-        labels = np.stack([b[1] for b in batches])
+        with timing.span("prepare"):
+            mask = self._select_mask_or_defer()
+            batches = []
+            for _ in range(cfg.k_iters):
+                batch = self.buffer.sample(self.rng, cfg.batch_size, t_now)
+                if batch is None:  # empty horizon: jrng consumed, no train
+                    return None
+                batches.append(batch)
+            frames = np.stack([b[0] for b in batches])
+            labels = np.stack([b[1] for b in batches])
         return mask, frames, labels
 
     def _run_phase_prepared(self, t_now: float, mask, frames,

@@ -209,44 +209,3 @@ def kernel_roofline_fraction(nbytes: int, measured_s: float, *,
         return None
     return (nbytes / (chips * hbm_bw)) / measured_s
 
-
-def serving_stage_report(drift: dict, device_kind: str | None = None) -> dict:
-    """Roofline-style summary of the serving pipeline's *measured* stage
-    timings, consuming a `repro.serving.obs.drift_report` dict.
-
-    Where `roofline_terms` ranks analytic lower bounds, this ranks the
-    stages the fused serving path actually ran (steady-state wall-clock,
-    compile excluded) and reports each stage's model efficiency — modeled
-    seconds / measured seconds, the fraction of `GPUCostModel`'s price the
-    real stacked executables achieve. ``bottleneck`` is the stage eating
-    the most measured steady time; a low ``model_efficiency`` there is
-    where re-pricing (or a faster kernel) pays first.
-
-    Stages whose timing hooks recorded analytic byte traffic (``nbytes`` —
-    the masked-Adam and top-k bounds above) additionally report
-    ``roofline_fraction`` when the run's ``device_kind`` is given:
-    measured steady wall-clock against the memory-bound time for those
-    bytes on that device (`kernel_roofline_fraction`). Without a device
-    kind (a CPU run) it is None: not measured."""
-    stages = {}
-    for stage, e in sorted(drift.items()):
-        meas, mod = e["measured_steady_s"], e["modeled_steady_s"]
-        nbytes = int(e.get("nbytes", 0))
-        stages[stage] = {
-            "measured_s": meas,
-            "modeled_s": mod,
-            "compile_s": e["compile_s"],
-            "calls": e["calls"],
-            "model_efficiency": (mod / meas) if meas > 0 else None,
-            "nbytes": nbytes,
-            "roofline_fraction": (
-                kernel_roofline_fraction(nbytes, meas,
-                                         device_kind=device_kind)
-                if nbytes and device_kind is not None else None),
-        }
-    measured = {k: v["measured_s"] for k, v in stages.items()}
-    return {
-        "stages": stages,
-        "bottleneck": (max(measured, key=measured.get) if measured else None),
-        "measured_total_s": sum(measured.values()),
-    }

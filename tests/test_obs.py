@@ -14,7 +14,6 @@ from _hyp import given, settings, st
 
 from repro.core import timing
 from repro.core.scheduler import GPUCostModel
-from repro.roofline.analysis import serving_stage_report
 from repro.serving import (
     ClientNetwork,
     FaultPlan,
@@ -411,24 +410,6 @@ def test_drift_report_against_known_cost_model():
     enc = d["encode_solo"]
     assert abs(enc["modeled_steady_s"] - cost.delta_comp_s(2_000_000)) < 1e-9
     assert abs(enc["measured_per_call_s"] - 0.05) < 1e-12
-
-
-def test_serving_stage_report_ranks_bottleneck():
-    cost = GPUCostModel(**PRICED)
-    stats = {
-        ("train_fused", (4, 20)): {"calls": 2, "first_calls": 1,
-                                   "first_s": 5.0, "steady_s": 0.4,
-                                   "nbytes": 0},
-        ("select_stacked", (4,)): {"calls": 2, "first_calls": 1,
-                                   "first_s": 1.0, "steady_s": 0.1,
-                                   "nbytes": 0},
-    }
-    rep = serving_stage_report(drift_report(cost, stats))
-    assert rep["bottleneck"] == "train_fused"
-    assert abs(rep["measured_total_s"] - 0.5) < 1e-12
-    tf = rep["stages"]["train_fused"]
-    assert tf["measured_s"] == 0.4 and tf["compile_s"] == 5.0
-    assert tf["model_efficiency"] is not None
 
 
 def test_debug_snapshot_unifies_hooks():

@@ -55,6 +55,7 @@ Stage records (`core.timing`): the host work around each launch is timed
 where it happens, as sibling spans on the dispatching thread: ``place``
 (stacking the group's state and moving its replay batches to the device,
 with the batch bytes sent) and ``commit`` (pulling the losses, unstacking
+the group's params, optimizer state and last update in one jitted launch,
 and adopting each member's state), beside ``select_stacked``,
 ``train_fused`` and ``encode_stacked``; ``prepare`` (replay sampling) is
 `AMSSession._prepare_phase_deferred`'s. Each is a profiler annotation
@@ -65,6 +66,7 @@ from __future__ import annotations
 import time
 from collections import defaultdict
 from concurrent.futures import ThreadPoolExecutor
+from functools import partial
 from typing import Hashable
 
 import jax
@@ -98,9 +100,21 @@ def stack_trees(trees: list):
     return _stack_impl(tuple(trees))
 
 
+@partial(jax.jit, static_argnums=1)
+def _unstack_impl(tree, n: int) -> tuple:
+    return tuple(jax.tree.map(lambda l: l[i], tree) for i in range(n))
+
+
 def unstack_tree(tree, n: int) -> list:
-    """Inverse of `stack_trees`: split the leading axis back into B trees."""
-    return [jax.tree.map(lambda l: l[i], tree) for i in range(n)]
+    """Inverse of `stack_trees`: split the leading axis back into B trees.
+
+    Jitted like the stack: every leaf's B slices come from ONE launch
+    (compile-cached by structure/shape and ``n``) instead of one eager
+    indexing dispatch per leaf per session — B x 657 dispatches for the
+    MobileNetV2 student's params, Adam state and last update. A slice is a
+    copy, so each output leaf is bit-identical to ``l[i]``; the launch
+    follows its committed inputs onto their device or sharding."""
+    return list(_unstack_impl(tree, n))
 
 
 def _dtype_name(leaf) -> str:
@@ -374,12 +388,16 @@ def fused_phase_fn(loss_and_grad, *, struct: Hashable, k_iters: int,
 # ran stacked (one launch / one transfer pair per fused group) instead of
 # per-session. The serving engine snapshots this around a run.
 _UPDATE_STATS = {"stacked_select_launches": 0, "stacked_select_sessions": 0,
-                 "stacked_encode_launches": 0, "stacked_encode_sessions": 0}
+                 "stacked_encode_launches": 0, "stacked_encode_sessions": 0,
+                 "stacked_unstack_launches": 0,
+                 "stacked_unstack_sessions": 0}
 
 
 def update_pipeline_info() -> dict:
     """Counters for the fused post-train update pipeline (stacked selection
-    launches + batched delta encodes and the sessions they covered)."""
+    launches + batched delta encodes and the sessions they covered), plus
+    the commit's unstack: ``stacked_unstack_launches`` (one per stacked
+    commit) and ``stacked_unstack_sessions`` (the states it handed back)."""
     return dict(_UPDATE_STATS)
 
 
@@ -442,8 +460,7 @@ def _stacked_masks(members, force_stack: bool, device=None):
         _UPDATE_STATS["stacked_select_sessions"] += len(deferred)
         if pure:
             return stacked_d
-        per = {j: jax.tree.map(lambda l, k=k: l[k], stacked_d)
-               for k, j in enumerate(deferred)}
+        per = dict(zip(deferred, unstack_tree(stacked_d, len(deferred))))
     else:
         per = {j: selection.gradient_guided_mask(members[j][1].u_prev, gamma)
                for j in deferred}
@@ -837,7 +854,8 @@ def _commit_stacked(members, t_now, out, results) -> None:
     """Encode the group's wire deltas (one batched device->host pull) and
     commit per-member state — the tail every stacked launch shares. The
     commit (losses pulled, state unstacked and adopted) is its own stage,
-    after the encode's."""
+    after the encode's; its unstack of ``(params, opt, u)`` is one jitted
+    launch for the whole group."""
     params, opt, u, losses, mask = out
     b = len(members)
     s0 = members[0][1]
@@ -846,9 +864,11 @@ def _commit_stacked(members, t_now, out, results) -> None:
     _UPDATE_STATS["stacked_encode_sessions"] += b
     with timing.span("commit"):
         losses = np.asarray(losses)
-        for j, (i, s, _, _, _), p_j, o_j, u_j in zip(
-                range(b), members, unstack_tree(params, b),
-                unstack_tree(opt, b), unstack_tree(u, b)):
+        states = unstack_tree((params, opt, u), b)
+        _UPDATE_STATS["stacked_unstack_launches"] += 1
+        _UPDATE_STATS["stacked_unstack_sessions"] += b
+        for j, (i, s, _, _, _), (p_j, o_j, u_j) in zip(
+                range(b), members, states):
             # the delta is already encoded (batched), so no per-member
             # mask slice is ever consumed — don't dispatch B tree-slicings
             results[i] = s._commit_phase(t_now, p_j, o_j, u_j,

@@ -69,6 +69,47 @@ def test_stack_unstack_roundtrip(b, n, m, seed):
         assert _leaves_equal(orig, got)
 
 
+def _state_like_tree(b, seed):
+    """A stacked ``(params, MaskedAdamState, mask)`` shaped like a fused
+    group's state: float32 leaves, the int32 step count (``(B,)`` stacked)
+    and bool masks."""
+    from repro.core.masked_adam import MaskedAdamState
+
+    rng = np.random.default_rng(seed)
+
+    def f32(*shape):
+        return jnp.asarray(rng.normal(size=(b,) + shape), jnp.float32)
+
+    params = {"conv": {"w": f32(3, 3, 4, 8), "b": f32(8)}, "head": f32(8, 5)}
+    opt = MaskedAdamState(m=jax.tree.map(lambda l: l * 0.1, params),
+                          v=jax.tree.map(jnp.square, params),
+                          count=jnp.arange(b, dtype=jnp.int32) + 7)
+    mask = jax.tree.map(lambda l: l > 0, params)
+    return params, opt, mask
+
+
+@pytest.mark.parametrize("b", [1, 4])
+def test_unstack_tree_matches_per_leaf_slices(b):
+    """The jitted unstack hands back exactly what per-leaf ``l[i]`` gives,
+    dtype and shape included, and a second call with the same structure
+    and ``n`` reuses the compiled program."""
+    tree = _state_like_tree(b, seed=b)
+    got = batched.unstack_tree(tree, b)
+    assert isinstance(got, list) and len(got) == b
+    for i, tree_i in enumerate(got):
+        want = jax.tree.map(lambda l: l[i], tree)
+        assert (jax.tree.structure(tree_i) == jax.tree.structure(want))
+        for x, y in zip(jax.tree.leaves(tree_i), jax.tree.leaves(want)):
+            assert x.dtype == y.dtype and x.shape == y.shape
+            assert np.array_equal(np.asarray(x), np.asarray(y))
+    assert got[0][1].count.dtype == jnp.int32 and got[0][1].count.shape == ()
+    assert got[0][2]["head"].dtype == jnp.bool_
+    size = batched._unstack_impl._cache_size()
+    again = batched.unstack_tree(_state_like_tree(b, seed=b + 100), b)
+    assert batched._unstack_impl._cache_size() == size
+    assert not _leaves_equal(again, got)
+
+
 def test_stack_trees_empty_raises():
     with pytest.raises(ValueError):
         batched.stack_trees([])
@@ -136,6 +177,42 @@ def test_fused_b1_force_stack_matches_to_tolerance():
     [d] = batched.train_phases_fused([b], 6.0, force_stack=True)
     assert d is not None
     assert _max_leaf_diff(a.params, b.params) < 1e-4
+
+
+def test_fused_commit_unstacks_in_one_launch(monkeypatch):
+    """A stacked commit splits params, optimizer state and last update in
+    ONE unstack launch, and each member adopts exactly its per-leaf slice
+    of the stacked outputs; a singleton group never unstacks."""
+    pre = _pretrained()
+    fleet = [_session(i, pre) for i in range(3)]
+    seen = []
+    real = batched._commit_stacked
+
+    def spy(members, t_now, out, results):
+        seen.append(out[:3])
+        real(members, t_now, out, results)
+
+    monkeypatch.setattr(batched, "_commit_stacked", spy)
+    batched.update_pipeline_reset()
+    deltas = batched.train_phases_fused(fleet, 6.0, force_stack=True)
+    assert all(d is not None for d in deltas)
+    info = batched.update_pipeline_info()
+    assert info["stacked_unstack_launches"] == 1
+    assert info["stacked_unstack_sessions"] == 3
+    [(params, opt, u)] = seen
+    for j, s in enumerate(fleet):
+        for got, stacked in ((s.params, params), (s.opt_state, opt),
+                             (s.u_prev, u)):
+            want = jax.tree.map(lambda l: l[j], stacked)
+            for x, y in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+                assert x.dtype == y.dtype and x.shape == y.shape
+                assert np.array_equal(np.asarray(x), np.asarray(y))
+    # a lone session takes the sequential path: no unstack at all
+    [d] = batched.train_phases_fused([_session(5, pre)], 6.0)
+    assert d is not None
+    info = batched.update_pipeline_info()
+    assert info["stacked_unstack_launches"] == 1
+    assert info["stacked_unstack_sessions"] == 3
 
 
 def test_fused_empty_buffer_yields_none_slot():

@@ -277,10 +277,38 @@ for t in (6.0, 14.0):
         n_ident += np.array_equal(np.asarray(r.values),
                                   np.asarray(g.values))
 info = batched.sharded_info()
+
+
+def adopted(s):
+    return jax.tree.leaves((s.params, s.opt_state, s.u_prev))
+
+
+# per-device dispatch: each group's adopted state stays on its device
+on_device = all(l.devices() == {dev}
+                for grp, dev in zip(_groups(b, 4, 1), jax.devices())
+                for s in grp for l in adopted(s))
+# spmd one-launch: each adopted leaf is placed as eager l[j] of the
+# session-sharded output places it
+c = _seg_sessions(4, seed0=700, size=12)
+outs, commit = [], batched._commit_stacked
+batched._commit_stacked = lambda m, t, out, r: (outs.append(out[:3]),
+                                                commit(m, t, out, r))
+train_phases_sharded(_groups(c, 4, 1), 6.0, devices=jax.devices(),
+                     spmd=True)
+batched._commit_stacked = commit
+[stacked] = outs
+spmd_placed = all(
+    got.sharding.is_equivalent_to(want.sharding, got.ndim)
+    and np.array_equal(np.asarray(got), np.asarray(want))
+    for j, s in enumerate(c)
+    for got, want in zip(adopted(s), jax.tree.leaves(
+        jax.tree.map(lambda l: l[j], stacked))))
 print(json.dumps({"masks_ok": masks_ok, "max_ulp": max_ulp,
                   "n_identical": n_ident,
                   "distinct_devices": info["distinct_devices"],
-                  "dispatch_launches": info["dispatch_launches"]}))
+                  "dispatch_launches": info["dispatch_launches"],
+                  "on_device": on_device, "spmd_placed": spmd_placed,
+                  "spmd_launches": batched.sharded_info()["spmd_launches"]}))
 """
 
 
@@ -305,3 +333,7 @@ def test_four_device_mesh_matches_single_device():
         f"4-device wire deltas drifted {out['max_ulp']} f16 ULP (>1)")
     assert out["distinct_devices"] == 4
     assert out["dispatch_launches"] == 8  # 4 groups x 2 phases
+    assert out["on_device"], "per-device commit moved adopted state"
+    assert out["spmd_launches"] == 1
+    assert out["spmd_placed"], (
+        "spmd commit placed adopted state unlike eager per-leaf slices")
